@@ -36,7 +36,6 @@ from repro.core.buckets import layout_for_tree
 from repro.core.costmodel import (ElasticMeshBudget, ElasticPlan,
                                   ElasticPlanError, plan_elastic_mesh)
 from repro.core.shadow import ShadowCluster
-from repro.dist import compat
 from repro.dist.sharding import ShardingRules
 
 __all__ = ["ElasticMeshBudget", "ElasticPlan", "ElasticPlanError",
@@ -62,9 +61,9 @@ def mesh_from_plan(plan: ElasticPlan, devices=None):
             f"{len(devices)} are visible")
     picked = [devices[r] for r in plan.survivors] if plan.survivors \
         else devices[:plan.n_ranks]
-    return compat.make_mesh(
+    return jax.make_mesh(
         plan.mesh_shape, plan.axis_names, devices=picked,
-        axis_types=(compat.AxisType.Auto,) * len(plan.mesh_shape))
+        axis_types=(jax.sharding.AxisType.Auto,) * len(plan.mesh_shape))
 
 
 def rules_from_plan(plan: ElasticPlan, devices=None) -> ShardingRules:

@@ -38,6 +38,12 @@ already-resident flats (bit-identical to K separate applies by
 construction — the acceptance bar, see tests/test_flat_shadow.py), while
 the trainer blocks only when the backlog would exceed the bound; that wait
 is surfaced as the ``apply-lag`` stall stage (obs/stalls.py).
+
+The plane lives on the host CPU, as the paper's shadow cluster does: the
+cluster picks ``jax.devices("cpu")[0]`` once and every node places its
+state, each staged delivery and each consolidated leaf there with
+``jax.device_put``. Nothing inherits the process's default device, which
+on a TPU host is the training chip.
 """
 from __future__ import annotations
 
@@ -63,6 +69,14 @@ from repro.optim.functional import (OptimizerConfig, UPDATE_FNS,
                                     UPDATE_FNS_FLAT)
 
 APPLY_TIMES_MAXLEN = 512       # recent-apply window kept per node
+
+# consolidate's flat -> leaf cut, compiled once per bucket and run where the
+# flat lives. A leaf that spans its whole bucket cuts to the identity; the
+# copy keeps it from being the flat buffer that the next apply donates.
+_unpack_on_device = jax.jit(
+    lambda bucket, flat: {k: jnp.copy(v) for k, v in
+                          unpack_bucket(bucket, flat, xp=jnp).items()},
+    static_argnums=0)
 
 
 class ConsolidationTimeout(RuntimeError):
@@ -143,11 +157,12 @@ class ShadowNode:
 
     def __init__(self, node_id: int, opt: OptimizerConfig,
                  layout: BucketLayout, bucket_ids: list[int],
-                 flat: bool = True,
+                 device, flat: bool = True,
                  apply_times_maxlen: int = APPLY_TIMES_MAXLEN):
         self.node_id = node_id
         self.opt = opt
         self.layout = layout
+        self.device = device           # every state buffer lives here
         self.flat = flat
         self.bucket_ids = sorted(bucket_ids)
         # hot path: resolved once here, not per apply (§6.3 timeliness)
@@ -203,17 +218,27 @@ class ShadowNode:
             self._update = jax.jit(self._update_fn)
 
     # -- state ---------------------------------------------------------------
+    def _put(self, x):
+        return jax.device_put(x, self.device)
+
+    def buffers(self) -> list:
+        """Every state array this node holds (flat buffers or leaves)."""
+        with self.state_lock:
+            return [*self._pf.values(), *self._mf.values(),
+                    *self._vf.values(), *self.params.values(),
+                    *self.mu.values(), *self.nu.values()]
+
     def bootstrap(self, params, mu, nu, step: int):
         """Install the replica (cold path: leaf trees -> flat partitions)."""
         if self.flat:
             pf, mf, vf = {}, {}, {}
             for bid in self.bucket_ids:
                 b = self._by_id[bid]
-                pf[bid] = jnp.asarray(pack_bucket_into(
+                pf[bid] = self._put(pack_bucket_into(
                     b, params, alloc_flat(b.size, bucket_dtype(b))))
-                mf[bid] = jnp.asarray(pack_bucket_into(
+                mf[bid] = self._put(pack_bucket_into(
                     b, mu, alloc_flat(b.size, np.float32)))
-                vf[bid] = jnp.asarray(pack_bucket_into(
+                vf[bid] = self._put(pack_bucket_into(
                     b, nu, alloc_flat(b.size, np.float32)))
             with self.state_lock:
                 self._pf, self._mf, self._vf = pf, mf, vf
@@ -221,29 +246,28 @@ class ShadowNode:
                 self.step = int(step)
             return
         for name in self._leaves:
-            self.params[name] = jnp.asarray(params[name])
-            self.mu[name] = jnp.asarray(mu[name])
-            self.nu[name] = jnp.asarray(nu[name])
+            self.params[name] = self._put(params[name])
+            self.mu[name] = self._put(mu[name])
+            self.nu[name] = self._put(nu[name])
         self.step = int(step)
 
     def snapshot(self) -> tuple[dict, dict, dict, int]:
         """Apply-atomic (params, mu, nu, step) leaf trees for this
-        partition — the cold flat -> leaf boundary used by consolidate."""
+        partition — the cold flat -> leaf boundary used by consolidate.
+        The leaves are fresh arrays on the node's device: the next apply
+        donates the flat buffers they are cut from."""
         with self.state_lock:
             if not self.flat:
                 return dict(self.params), dict(self.mu), dict(self.nu), \
                     self.step
-            pf = {bid: np.asarray(a) for bid, a in self._pf.items()}
-            mf = {bid: np.asarray(a) for bid, a in self._mf.items()}
-            vf = {bid: np.asarray(a) for bid, a in self._vf.items()}
-            step = self.step
-        params, mu, nu = {}, {}, {}
-        for bid in self.bucket_ids:
-            b = self._by_id[bid]
-            params.update(unpack_bucket(b, pf[bid], xp=np))
-            mu.update(unpack_bucket(b, mf[bid], xp=np))
-            nu.update(unpack_bucket(b, vf[bid], xp=np))
-        return params, mu, nu, step
+            params, mu, nu = {}, {}, {}
+            for bid in self.bucket_ids:
+                b = self._by_id[bid]
+                params.update(_unpack_on_device(b, self._pf[bid]))
+                mu.update(_unpack_on_device(b, self._mf[bid]))
+                nu.update(_unpack_on_device(b, self._vf[bid]))
+            jax.block_until_ready((params, mu, nu))
+            return params, mu, nu, self.step
 
     def snapshot_dirty(self, force_all: bool = False
                        ) -> tuple[dict, int]:
@@ -328,9 +352,10 @@ class ShadowNode:
     def _apply(self, step, lr, flats, grad_scale):
         t0 = time.perf_counter()
         if self.flat:
-            step_f = jnp.float32(step)
-            lr_f = jnp.float32(lr)
-            scale_f = jnp.float32(grad_scale)
+            # host scalars: they follow the committed state to its device
+            step_f = np.float32(step)
+            lr_f = np.float32(lr)
+            scale_f = np.float32(grad_scale)
             # the whole update runs under state_lock: inputs are DONATED to
             # the fused kernel, so a concurrent snapshot must never read
             # them mid-apply (it would see invalidated buffers, not a torn
@@ -341,9 +366,9 @@ class ShadowNode:
                 # (host->device transfer) before dispatching bucket i's
                 # fused update, so the transfer overlaps the async apply;
                 # same per-bucket update stream, so bit-identical
-                nxt = jnp.asarray(flats[ids[0]]) if ids else None
+                nxt = self._put(flats[ids[0]]) if ids else None
                 for j, bid in enumerate(ids):
-                    g, nxt = nxt, (jnp.asarray(flats[ids[j + 1]])
+                    g, nxt = nxt, (self._put(flats[ids[j + 1]])
                                    if j + 1 < len(ids) else None)
                     p, m, v = self._update_flat(
                         self._pf[bid], g,
@@ -359,12 +384,12 @@ class ShadowNode:
         grads = {}
         for bid in self.bucket_ids:
             bucket = self._by_id[bid]
-            grads.update(unpack_bucket(bucket, jnp.asarray(flats[bid]),
+            grads.update(unpack_bucket(bucket, self._put(flats[bid]),
                                        xp=jnp))
         grads = {k: v for k, v in grads.items() if k in self.params}
         p, m, v = self._update(self.params, self.mu, self.nu, grads,
-                               jnp.float32(step), jnp.float32(lr),
-                               jnp.float32(grad_scale))
+                               np.float32(step), np.float32(lr),
+                               np.float32(grad_scale))
         jax.block_until_ready(p)
         with self.state_lock:
             self.params.update(p)
@@ -415,10 +440,13 @@ class ShadowCluster:
         # custom assignment may be injected (tests sweep random mappings)
         self.assignment = dict(assignment) if assignment is not None \
             else assign_buckets(layout, n_nodes)
+        # the one placement decision: the whole plane on the host CPU
+        self.device = jax.devices("cpu")[0]
         self.nodes = [
             ShadowNode(i, opt, layout,
                        [b for b, n in self.assignment.items() if n == i],
-                       flat=flat, apply_times_maxlen=apply_times_maxlen)
+                       self.device, flat=flat,
+                       apply_times_maxlen=apply_times_maxlen)
             for i in range(n_nodes)
         ]
         self.async_mode = async_mode

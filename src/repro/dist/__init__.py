@@ -6,4 +6,3 @@ and explicitly in :mod:`repro.dist.collectives`) is Checkmate's capture
 point: each device owns a disjoint slice of the fully-reduced gradients, so
 the network already carries everything a checkpoint needs.
 """
-from repro.dist import compat  # noqa: F401  (jax 0.4.x mesh API shims)

@@ -22,8 +22,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro.dist.compat import shard_map
-
 
 def ring_all_reduce_rs_ag(x, mesh, axis: str):
     """Ring AllReduce decomposed as ReduceScatter -> AllGather.
@@ -65,11 +63,11 @@ def ring_all_reduce_rs_ag(x, mesh, axis: str):
 
         return acc.reshape(-1), owned
 
-    full, shards = shard_map(
+    full, shards = jax.shard_map(
         ring, mesh=mesh,
         in_specs=P(),                    # every device holds its local copy
         out_specs=(P(), P(axis)),        # replicated result, sharded chunks
-        check_rep=False,
+        check_vma=False,
     )(padded)
 
     if pad:

@@ -17,16 +17,13 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro.dist import compat
-from repro.dist.compat import shard_map
-
 
 def make_pp_mesh(n_stages: int, n_data: int):
     """("stage", "data") mesh over the first n_stages * n_data devices."""
-    return compat.make_mesh(
+    return jax.make_mesh(
         (n_stages, n_data), ("stage", "data"),
         devices=jax.devices()[:n_stages * n_data],
-        axis_types=(compat.AxisType.Auto,) * 2)
+        axis_types=(jax.sharding.AxisType.Auto,) * 2)
 
 
 def pipeline_apply(fn, stage_weights, microbatches, mesh):
@@ -60,11 +57,11 @@ def pipeline_apply(fn, stage_weights, microbatches, mesh):
         outs = jnp.where(stage == S - 1, outs, jnp.zeros_like(outs))
         return jax.lax.psum(outs, "stage")
 
-    return shard_map(
+    return jax.shard_map(
         run, mesh=mesh,
         in_specs=(P("stage"), P()),
         out_specs=P(),
-        check_rep=False,
+        check_vma=False,
     )(stage_weights, microbatches)
 
 

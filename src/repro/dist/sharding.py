@@ -34,7 +34,6 @@ import math
 import jax
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from repro.dist import compat
 
 # Logical names that map to the tensor-parallel ("model") axis. Weight dims
 # and activation dims are listed separately only for documentation — they
@@ -121,13 +120,23 @@ class ShardingRules:
             x, self.sharding(*logical, dims=x.shape))
 
 
+def make_local_mesh(chips: int = 0):
+    """(data=chips, model=1) mesh over the first ``chips`` devices present
+    (0 = all of them): the data-parallel training mesh of one host."""
+    devices = jax.devices()
+    chips = chips or len(devices)
+    if chips > len(devices):
+        raise ValueError(f"{chips} chip(s) requested, {len(devices)} present")
+    return jax.make_mesh(
+        (chips, 1), ("data", "model"), devices=devices[:chips],
+        axis_types=(jax.sharding.AxisType.Auto,) * 2)
+
+
 def make_smoke_mesh():
-    """Single-host ("data", "model") mesh that works on 1 CPU device.
+    """Single-device ("data", "model") mesh that works on 1 CPU device.
 
     Smoke tests run the full GSPMD code path (constraints, logical
     resolution, ZeRO-1 specs) with every axis extent 1, so the lowered
     program is collective-free but structurally identical to a pod run.
     """
-    return compat.make_mesh(
-        (1, 1), ("data", "model"), devices=jax.devices()[:1],
-        axis_types=(compat.AxisType.Auto,) * 2)
+    return make_local_mesh(1)

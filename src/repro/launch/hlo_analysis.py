@@ -389,8 +389,6 @@ def analyze_compiled(compiled) -> dict:
     """Cost summary dict for a jax.stages.Compiled (per-device numbers)."""
     cost = analyze_hlo_text(compiled.as_text())
     xla = compiled.cost_analysis() or {}
-    if isinstance(xla, (list, tuple)):        # jax 0.4.x: list of one dict
-        xla = xla[0] if xla else {}
     mem = compiled.memory_analysis()
     return {
         "flops_per_device": cost.flops,

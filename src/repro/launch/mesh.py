@@ -7,8 +7,6 @@ from __future__ import annotations
 
 import jax
 
-from repro.dist import compat
-
 
 def make_production_mesh(*, multi_pod: bool = False):
     """Single pod: (data=16, model=16) = 256 chips.
@@ -19,6 +17,5 @@ def make_production_mesh(*, multi_pod: bool = False):
     ndev = 1
     for s in shape:
         ndev *= s
-    devices = jax.devices()[:ndev]
-    return compat.make_mesh(shape, axes, devices=devices,
-                            axis_types=(compat.AxisType.Auto,) * len(axes))
+    return jax.make_mesh(shape, axes, devices=jax.devices()[:ndev],
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))

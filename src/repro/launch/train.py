@@ -4,25 +4,46 @@
         --reduced --steps 50 --batch 8 --seq 64 --shadow-nodes 2 \
         --checkpointer checkmate --fail-at 20,35
 
-On this CPU container use --reduced (tiny same-family config). On a real
-pod, drop --reduced and pass --mesh single|multi.
+The job trains on a (data=chips, model=1) mesh over the chips this host
+has (``--chips``, default all of them). ``--reduced`` swaps in a tiny
+same-family config for a CPU; ``--layers N`` keeps every published width
+and cuts the depth to N whole layers, which is how a published model is
+sized to one chip (``chip_smoke.py`` trains gpt3-xl that way). Production
+256/512-chip meshes are lowered by `repro.launch.dryrun`.
 """
 from __future__ import annotations
 
 import argparse
+import dataclasses
 import json
+import os
 import time
+from pathlib import Path
 
-import numpy as np
+# one fixed directory in the checkout: the cache key includes the path
+COMPILE_CACHE_DIR = Path(__file__).resolve().parents[3] / ".jax_cache"
 
 
-def main():
+def use_compile_cache():
+    """Keep JAX's persistent compile cache where ``JAX_COMPILATION_CACHE_DIR``
+    says (JAX reads that itself), else in `COMPILE_CACHE_DIR`."""
+    if "JAX_COMPILATION_CACHE_DIR" not in os.environ:
+        import jax
+        jax.config.update("jax_compilation_cache_dir", str(COMPILE_CACHE_DIR))
+
+
+def parse_args(argv=None):
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="tinyllama-1.1b")
     ap.add_argument("--reduced", action="store_true")
+    ap.add_argument("--layers", type=int, default=0,
+                    help="keep this many whole layers at published widths "
+                         "(0 = the config's depth)")
     ap.add_argument("--steps", type=int, default=30)
     ap.add_argument("--batch", type=int, default=8)
     ap.add_argument("--seq", type=int, default=64)
+    ap.add_argument("--microbatches", type=int, default=0,
+                    help="gradient-accumulation steps (0 = the config's)")
     ap.add_argument("--lr", type=float, default=1e-3)
     ap.add_argument("--optimizer", default="adamw")
     ap.add_argument("--checkpointer", default="checkmate",
@@ -42,15 +63,31 @@ def main():
                     help="comma-separated steps to inject failures at")
     ap.add_argument("--compress", action="store_true",
                     help="int8 gradient compression with error feedback")
-    ap.add_argument("--mesh", default="smoke", choices=["smoke", "single", "multi"])
+    ap.add_argument("--chips", type=int, default=0,
+                    help="data-parallel width: the first N devices "
+                         "(0 = every device present)")
     ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--trace-out", default=None,
                     help="write a Chrome/Perfetto trace of the run "
                          "(enables the tracing session)")
     ap.add_argument("--metrics-out", default=None,
                     help="write the end-of-run metrics snapshot JSON")
-    args = ap.parse_args()
+    return ap.parse_args(argv)
 
+
+@dataclasses.dataclass
+class RunResult:
+    cfg: object                # the ModelConfig trained, depth cut included
+    state: object              # the live TrainState after the last step
+    stats: object              # repro.train.loop.LoopStats
+    checkpointer: object
+    report: dict
+    digest: str
+
+
+def run(args) -> RunResult:
+    """Build the job ``args`` describes and train it. The caller owns the
+    shadow plane (``result.checkpointer.shadow``) and shuts it down."""
     import jax
     import repro.configs as C
     from repro.core.buckets import layout_for_tree
@@ -63,8 +100,7 @@ def main():
                                        SyncCheckpointer)
     from repro.core.recovery import FailurePlan
     from repro.core.shadow import ShadowCluster
-    from repro.dist.sharding import ShardingRules, make_smoke_mesh
-    from repro.launch.mesh import make_production_mesh
+    from repro.dist.sharding import ShardingRules, make_local_mesh
     from repro.optim import OptimizerConfig
     from repro.optim.schedules import cosine_schedule
     from repro.train.loop import train
@@ -72,13 +108,13 @@ def main():
     from repro import obs
     from repro.obs.publish import collect_run, render_digest
 
-    cfg = C.get(args.arch)
-    if args.reduced:
-        cfg = cfg.reduced()
-    if args.mesh == "smoke":
-        mesh = make_smoke_mesh()
-    else:
-        mesh = make_production_mesh(multi_pod=args.mesh == "multi")
+    published = C.get(args.arch)
+    cfg = published.reduced() if args.reduced else published
+    if args.layers:
+        cfg = dataclasses.replace(cfg, num_layers=args.layers)
+    if args.microbatches:
+        cfg = dataclasses.replace(cfg, microbatches=args.microbatches)
+    mesh = make_local_mesh(args.chips)
     rules = ShardingRules(mesh, fsdp=cfg.fsdp)
     opt = OptimizerConfig(name=args.optimizer, lr=args.lr)
     lr_fn = cosine_schedule(args.lr, warmup=5, total=args.steps)
@@ -117,11 +153,15 @@ def main():
                else None)
     ob = session.__enter__() if session is not None else None
     t0 = time.time()
+    # hand the loop the only reference to the initial state: its first step
+    # donates it, and the host copy the shadow bootstrap cached on it must
+    # not outlive it
+    init, state0 = [state0], None
     try:
         state, stats = train(cfg, rules, steps=args.steps, batch=args.batch,
                              seq=args.seq, opt=opt, lr_fn=lr_fn,
                              checkpointer=ck, failure_plan=plan,
-                             seed=args.seed, state=state0)
+                             seed=args.seed, state=init.pop())
         wall = time.time() - t0
         reg = ob.metrics if ob is not None else obs.MetricsRegistry()
         digest_snap = collect_run(reg, checkpointer=ck)
@@ -134,10 +174,13 @@ def main():
             session.__exit__(None, None, None)
 
     report = {
-        "arch": cfg.name, "steps": stats.steps,
+        "arch": cfg.name, "layers": cfg.num_layers,
+        "layers_published": published.num_layers,
+        "chips": mesh.devices.size, "steps": stats.steps,
         "final_loss": stats.losses[-1] if stats.losses else None,
         "throughput_it_s": round(stats.throughput, 3),
         "mean_iter_s": round(stats.mean_iter, 4),
+        "steady_iter_s": round(stats.steady_iter, 4),
         "checkpoints": ck.n_checkpoints,
         "stall_total_s": round(ck.stall_total, 4),
         "failures": stats.failures, "recoveries": stats.recoveries,
@@ -153,11 +196,21 @@ def main():
             "mean_apply_s": round(s.mean_apply_s, 4),
             "max_queue_depth": s.max_queue_depth,
         }
-        shadow.shutdown()
-    print(json.dumps(report, indent=2))
     # satellite: one-screen end-of-run digest sourced from the metrics
     # registry (same numbers `python -m repro.obs summary` reports)
-    print(render_digest(digest_snap, ck=ck))
+    return RunResult(cfg, state, stats, ck, report,
+                     render_digest(digest_snap, ck=ck))
+
+
+def main(argv=None):
+    args = parse_args(argv)
+    use_compile_cache()
+    res = run(args)
+    shadow = getattr(res.checkpointer, "shadow", None)
+    if shadow is not None:
+        shadow.shutdown()
+    print(json.dumps(res.report, indent=2))
+    print(res.digest)
 
 
 if __name__ == "__main__":

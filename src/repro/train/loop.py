@@ -145,6 +145,7 @@ def train(cfg: ModelConfig, rules: ShardingRules, *,
                 state, metrics, grads = step_fn(state, dbatch)
                 jax.block_until_ready(metrics["loss"])
         except TrainingFailure:
+            state = None     # lost with the failure; frees HBM for the restore
             with ob.tracer.span("recovery.restore", track="recovery",
                                 args={"failed_step": step + 1}):
                 restored = checkpointer.restore()
@@ -202,6 +203,11 @@ def train(cfg: ModelConfig, rules: ShardingRules, *,
             # Copy-persist baselines never read grads, so they don't pay it.
             with ob.tracer.span("capture.d2h", args={"step": step}):
                 host_grads = {k: np.asarray(v) for k, v in grads.items()}
+        # the device gradients are dead once copied: free them before the
+        # next step runs, so two steps' gradients never share HBM
+        for g in jax.tree.leaves(grads):
+            g.delete()
+        del grads
         stall = checkpointer.on_step(StepEvent(
             step=step, grads=host_grads, lr=lr, grad_scale=scale,
             iter_time=iter_time,

@@ -33,8 +33,6 @@ def test_scan_multiplies_trip_count():
     assert abs(cost.flops - 8 * (matmul + 512 * 512)) / (8 * matmul) < 0.01
     # XLA's own analysis counts the body once — ours must be ~8x larger
     xla = _compile(f, x, w).cost_analysis()
-    if isinstance(xla, (list, tuple)):      # jax 0.4.x: list of one dict
-        xla = xla[0]
     assert cost.flops > 7 * xla["flops"]
 
 

@@ -99,3 +99,19 @@ def test_bucket_pack_matches_ref():
     back = ref.bucket_unpack_ref(flat[:total], [x.shape for x in leaves])
     for a, b in zip(back, leaves):
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+def test_fused_adamw_flat_follows_the_lowering_platform(monkeypatch):
+    """The shadow's fused AdamW is the jnp pass when lowered for the CPU and
+    the Mosaic kernel when lowered for a TPU, in the same process, without
+    asking which backend is the default."""
+    def no_default_backend():
+        raise AssertionError("kernel choice read the default backend")
+    monkeypatch.setattr(jax, "default_backend", no_default_backend)
+    flat = jax.ShapeDtypeStruct((1000,), jnp.float32)
+    scalar = jax.ShapeDtypeStruct((), jnp.float32)
+    traced = ops.fused_adamw_flat.trace(flat, flat, flat, flat, scalar, scalar)
+    cpu = traced.lower(lowering_platforms=("cpu",)).as_text()
+    tpu = traced.lower(lowering_platforms=("tpu",)).as_text()
+    assert "tpu_custom_call" not in cpu
+    assert "tpu_custom_call" in tpu

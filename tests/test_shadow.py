@@ -8,6 +8,7 @@ import jax.numpy as jnp
 
 import repro.configs as C
 from repro.core.buckets import layout_for_tree
+from repro.core.channel import InProcessChannel, StepEvent
 from repro.core.shadow import ShadowCluster, plan_shadow_nodes
 from repro.dist.sharding import ShardingRules, make_smoke_mesh
 from repro.optim import OptimizerConfig, apply_updates, init_state
@@ -111,3 +112,43 @@ def test_plan_shadow_nodes(setup):
     n2, _ = plan_shadow_nodes(layout, OptimizerConfig(),
                               iter_time_s=max(t / 4, 1e-6), trial_tree=tree)
     assert n2 >= n
+
+
+@pytest.mark.parametrize("flat", [True, False])
+@pytest.mark.parametrize("cap_bytes", [16, 32 * 1024])
+def test_shadow_state_lives_on_the_clusters_device(setup, flat, cap_bytes):
+    """Every node buffer and every consolidated leaf sits on the device the
+    cluster chose (the host CPU), and a consolidated leaf is a copy: the
+    next apply, which donates the node's buffers, leaves it intact. At
+    ``cap_bytes=16`` every leaf is a bucket of its own, so a leaf spans
+    its whole flat buffer."""
+    cfg, rules, state0 = setup
+    layout = layout_for_tree(state0.params, cap_bytes=cap_bytes)
+    shadow = ShadowCluster(layout, OptimizerConfig(name="adamw", lr=1e-3),
+                           n_nodes=2, flat=flat)
+    assert shadow.device == jax.devices("cpu")[0]
+    shadow.bootstrap(state0.params, state0.mu, state0.nu, 0)
+    chan = InProcessChannel()
+    chan.open(layout)
+
+    def deliver(step):
+        chan.send(StepEvent(step=step, lr=1e-3,
+                            grads=_random_grads(state0.params, step)))
+        for d in chan.poll():
+            shadow.on_delivery(d)
+
+    deliver(1)
+    for node in shadow.nodes:
+        buffers = node.buffers()
+        assert buffers
+        assert all(a.devices() == {shadow.device} for a in buffers)
+    ckpt = shadow.consolidate()
+    before = {k: np.array(v) for k, v in ckpt["params"].items()}
+    deliver(2)
+    for part in ("params", "mu", "nu"):
+        assert set(ckpt[part]) == set(state0.params)
+        for leaf in ckpt[part].values():
+            assert isinstance(leaf, jax.Array)
+            assert leaf.devices() == {shadow.device}
+    for k, v in ckpt["params"].items():
+        assert np.array_equal(np.asarray(v), before[k]), k

@@ -47,6 +47,35 @@ def test_end_to_end_checkmate_async(env):
     shadow.shutdown()
 
 
+@pytest.mark.parametrize("consumer", [True, False])
+def test_loop_frees_each_steps_device_gradients(env, consumer):
+    """Once a step's gradients are captured (or, with no consumer, at once)
+    the loop holds none on the device: at each step hook every parameter
+    shape is live exactly as often as params, mu and nu hold it, so two
+    steps' gradients never share device memory."""
+    _, rules, opt = env
+    # widths no other test uses, so only this run's arrays match them
+    cfg = C.get("llama3.2-3b").reduced(d_model=56, d_ff=120, vocab_size=97)
+    s0 = make_train_state(jax.random.PRNGKey(0), cfg, rules)
+    shapes = [v.shape for v in s0.params.values() if v.ndim >= 2]
+    ck = None
+    if consumer:
+        shadow = ShadowCluster(layout_for_tree(s0.params), opt)
+        shadow.bootstrap(s0.params, s0.mu, s0.nu, 0)
+        ck = CheckmateCheckpointer(shadow)
+    seen = []
+
+    def hook(step, state, stats):
+        live = [a.shape for a in jax.live_arrays()
+                if a.dtype == jnp.float32 and a.shape in shapes]
+        seen.append(all(live.count(s) == 3 * shapes.count(s)
+                        for s in shapes))
+
+    train(cfg, rules, steps=3, batch=4, seq=16, opt=opt, state=s0,
+          checkpointer=ck, step_hook=hook)
+    assert seen == [True] * 3
+
+
 def test_loss_decreases(env):
     cfg, rules, opt = env
     _, stats = train(cfg, rules, steps=12, batch=8, seq=32, opt=opt, seed=5)

@@ -1,0 +1,13 @@
+"""CPU tests of the benchmark harness. They run with ``JAX_PLATFORMS=cpu``
+and load no TPU library:
+
+    JAX_PLATFORMS=cpu python -m pytest -q bench/tests
+"""
+import sys
+from pathlib import Path
+
+ROOT = Path(__file__).resolve().parents[2]
+for p in (ROOT, ROOT / "src"):
+    if str(p) not in sys.path:
+        sys.path.insert(0, str(p))
+

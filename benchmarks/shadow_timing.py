@@ -58,11 +58,11 @@ def run():
                              checkpointer=CheckmateCheckpointer(shadow))
             st = shadow.stats()
             tree = {k: np.asarray(v) for k, v in s0.params.items()}
-            n_min, t_apply = plan_shadow_nodes(layout, opt, stats.steady_iter,
+            n_min, t_apply = plan_shadow_nodes(layout, opt, stats.step_s,
                                                tree)
-            keeps_up = st.mean_apply_s < stats.steady_iter
-            csv_row(f"fig7.{cfg.name}.b{batch}", stats.steady_iter * 1e6,
-                    f"iter={stats.steady_iter*1e3:.0f}ms "
+            keeps_up = st.mean_apply_s < stats.step_s
+            csv_row(f"fig7.{cfg.name}.b{batch}", stats.step_s * 1e6,
+                    f"iter={stats.step_s*1e3:.0f}ms "
                     f"opt_step={st.mean_apply_s*1e3:.1f}ms "
                     f"min_nodes={n_min} keeps_up={keeps_up}")
 

@@ -50,12 +50,12 @@ def run():
         ck, s0 = make_ck(name)
         _, stats = train(cfg, rules, steps=STEPS, batch=BATCH, seq=SEQ,
                          opt=opt, checkpointer=ck, state=s0)
-        it = stats.steady_iter
+        it = stats.step_s                  # stalls inside
         stall = ck.stall_total / max(ck.n_checkpoints, 1)
         if name == "no_checkpoint":
             base_iter = it
-        slowdown = (it + stall) / base_iter
-        csv_row(f"fig2.{name}", (it + stall) * 1e6,
+        slowdown = it / base_iter
+        csv_row(f"fig2.{name}", it * 1e6,
                 f"iter={it*1e3:.0f}ms stall={stall*1e3:.0f}ms "
                 f"slowdown={slowdown:.2f}x")
         if hasattr(ck, "shadow"):

@@ -69,11 +69,11 @@ def main():
         "steps": stats.steps,
         "loss_first": round(stats.losses[0], 3),
         "loss_last": round(float(np.mean(stats.losses[-5:])), 3),
-        "steady_iter_s": round(stats.steady_iter, 2),
+        "tokens_per_s": round(stats.tokens_per_s, 1),
         "recoveries": stats.recoveries,
         "checkpoints": ckpt["step"],
         "shadow_mean_apply_s": round(s.mean_apply_s, 3),
-        "shadow_keeps_up": s.mean_apply_s < stats.steady_iter,
+        "shadow_keeps_up": s.mean_apply_s < stats.step_s,
         "shadow_bit_identical": exact,
         "wall_s": round(wall, 1),
     }, indent=2))

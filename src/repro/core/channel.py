@@ -267,9 +267,13 @@ class InProcessChannel:
         assert self._layout is not None, "open() before send()"
         ob = _obs.get()
         t0 = time.perf_counter()
+        pack = {"step": event.step}
+        if event.flats is None:          # adopted flats were packed upstream
+            pack.update(bytes=self._layout.total_bytes,
+                        buckets=len(self._layout.buckets))
         with ob.tracer.span("channel.send", args={"step": event.step,
                                                   "channel": self.name}):
-            with ob.tracer.span("bucket.pack", args={"step": event.step}):
+            with ob.tracer.span("bucket.pack", args=pack):
                 flats = _flats_from_event(self._layout, event)
             self._pending.append(Delivery(
                 step=event.step, lr=event.lr, grad_scale=event.grad_scale,
@@ -278,6 +282,9 @@ class InProcessChannel:
         self.last_send_parts = {"send": dt}
         ob.metrics.counter("channel_sends_total", "Gradient sends").inc(
             1, channel=self.name)
+        ob.metrics.counter("channel_pack_bytes_total",
+                           "Bytes packed into the wire layout").inc(
+            pack.get("bytes", 0), channel=self.name)
         return dt
 
     def poll(self) -> list[Delivery]:

@@ -557,35 +557,44 @@ class ShadowCluster:
         previously lost to :meth:`kill_node` (the resync that follows a
         shadow-node death hands every node a fresh partition).
         """
-        params = {k: np.asarray(v) for k, v in params.items()}
-        mu = {k: np.asarray(v) for k, v in mu.items()}
-        nu = {k: np.asarray(v) for k, v in nu.items()}
-        # a full-state install supersedes any still-queued deliveries: with
-        # a lagged backlog, replaying a pre-resync gradient onto the freshly
-        # seeded state would regress it (no-op when queues are drained, the
-        # normal case)
-        for q in self._queues:
-            try:
-                while True:
-                    item = q.get_nowait()
-                    if item is None:      # never eat a shutdown sentinel
-                        q.put(None)       # (task_done below pairs our get
-                    q.task_done()         # with the re-put's increment)
-                    if item is None:
-                        break
-            except queue.Empty:
-                pass
-            while self._pending(q):       # an in-flight apply (already off
-                time.sleep(0.001)         # the queue) finishes on the OLD
-            #                               state before the install below
-        self.dead_nodes.clear()
-        for node in self.nodes:
-            node.bootstrap(params, mu, nu, step)
-        self.train_step_seen = int(step)
-        if self.durability is not None:
-            # cold path: force a base flush so a full restore point exists
-            # from the moment the replica is (re-)seeded
-            self.durability.on_bootstrap(int(step))
+        span = _obs.get().tracer.span
+        with span("shadow.bootstrap", track="shadow",
+                  args={"step": int(step)}):
+            d2h = {}
+            with span("shadow.bootstrap.d2h", track="shadow", args=d2h):
+                params, mu, nu = ({k: np.asarray(v) for k, v in t.items()}
+                                  for t in (params, mu, nu))
+                d2h["bytes"] = sum(v.nbytes for t in (params, mu, nu)
+                                   for v in t.values())
+            # a full-state install supersedes any still-queued deliveries:
+            # with a lagged backlog, replaying a pre-resync gradient onto
+            # the freshly seeded state would regress it (no-op when queues
+            # are drained, the normal case)
+            for q in self._queues:
+                try:
+                    while True:
+                        item = q.get_nowait()
+                        if item is None:      # never eat a shutdown sentinel
+                            q.put(None)       # (task_done below pairs our
+                        q.task_done()         # get with the re-put's inc)
+                        if item is None:
+                            break
+                except queue.Empty:
+                    pass
+                while self._pending(q):       # an in-flight apply (already
+                    time.sleep(0.001)         # off the queue) finishes on
+                #                               the OLD state before the
+                #                               install below
+            self.dead_nodes.clear()
+            for node in self.nodes:
+                with span("shadow.bootstrap.install", track="shadow",
+                          args={"node": node.node_id}):
+                    node.bootstrap(params, mu, nu, step)
+            self.train_step_seen = int(step)
+            if self.durability is not None:
+                # cold path: force a base flush so a full restore point
+                # exists from the moment the replica is (re-)seeded
+                self.durability.on_bootstrap(int(step))
 
     def kill_node(self, node_id: int):
         """Simulated shadow-node death: the node's partition (params + both

@@ -178,9 +178,8 @@ def run(args) -> RunResult:
         "layers_published": published.num_layers,
         "chips": mesh.devices.size, "steps": stats.steps,
         "final_loss": stats.losses[-1] if stats.losses else None,
-        "throughput_it_s": round(stats.throughput, 3),
+        "tokens_per_s": round(stats.tokens_per_s, 1),
         "mean_iter_s": round(stats.mean_iter, 4),
-        "steady_iter_s": round(stats.steady_iter, 4),
         "checkpoints": ck.n_checkpoints,
         "stall_total_s": round(ck.stall_total, 4),
         "failures": stats.failures, "recoveries": stats.recoveries,

@@ -14,6 +14,14 @@ process tracks in the export:
   via ``fabric_advance``, so a multi-step run reads as a contiguous
   virtual-time timeline.
 
+While enabled, every host span is also a ``jax.profiler.TraceAnnotation``
+of the same name, so a profiler session (``jax.profiler.trace``) records
+it on its own clock, in the XPlane's host plane beside the device's
+operations; with no session running that costs one TraceMe check.
+Instants and fabric spans are not mirrored. Each span exports the name
+of the span open around it on the same thread as ``args["parent"]``, so
+self time (a span less its children) needs no guess from overlaps.
+
 The tracer is *near-zero-cost when disabled*: ``span()`` returns one
 shared no-op context manager and ``instant``/``fabric_span`` return
 immediately, so hot paths may call them unconditionally. ``maxlen`` makes
@@ -66,7 +74,8 @@ NULL_SPAN = _NullSpan()
 
 
 class _Span:
-    __slots__ = ("tr", "name", "track", "cat", "args", "t0")
+    __slots__ = ("tr", "name", "track", "cat", "args", "t0", "depth",
+                 "annotation")
 
     def __init__(self, tr, name, track, cat, args):
         self.tr = tr
@@ -76,13 +85,26 @@ class _Span:
         self.args = args
 
     def __enter__(self):
+        stack = self.tr._stack()
+        self.depth = len(stack)
+        stack.append(self.name)
+        # the bare name: the XPlane event keeps it letter for letter
+        self.annotation = self.tr._annotation(self.name)
+        self.annotation.__enter__()
         self.t0 = self.tr._clock()
         return self
 
     def __exit__(self, *exc):
         tr = self.tr
+        t1 = tr._clock()
+        self.annotation.__exit__(None, None, None)
+        stack = tr._stack()
+        args = self.args
+        if self.depth:
+            args = {**(args or {}), "parent": stack[self.depth - 1]}
+        del stack[self.depth:]
         tr._emit(self.name, HOST_PID, self.track, self.cat,
-                 self.t0 - tr._t0, tr._clock() - tr._t0, self.args)
+                 self.t0 - tr._t0, t1 - tr._t0, args)
         return False
 
 
@@ -98,9 +120,20 @@ class Tracer:
         self._tracks: dict[tuple, int] = {}
         self._lock = threading.Lock()
         self._seq = 0
+        self._local = threading.local()    # per-thread stack of open spans
+        self._annotation = None
+        if enabled:
+            from jax.profiler import TraceAnnotation
+            self._annotation = TraceAnnotation
         self.fabric_base_s = 0.0           # virtual-time offset of this step
 
     # -- emission ------------------------------------------------------------
+    def _stack(self) -> list:
+        stack = getattr(self._local, "stack", None)
+        if stack is None:
+            stack = self._local.stack = []
+        return stack
+
     def _tid(self, pid: int, track: str) -> int:
         key = (pid, track)
         tid = self._tracks.get(key)
@@ -126,7 +159,8 @@ class Tracer:
     # -- host clock domain ---------------------------------------------------
     def span(self, name: str, track: str = "train", cat: str = "host",
              args: Optional[dict] = None):
-        """Context manager timing one host-side stage; no-op when disabled."""
+        """Context manager timing one host-side stage, mirrored into the
+        profiler's trace; no-op when disabled."""
         if not self.enabled:
             return NULL_SPAN
         return _Span(self, name, track, cat, args)

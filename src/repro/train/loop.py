@@ -46,6 +46,8 @@ class LoopStats:
     losses: list = field(default_factory=list)
     iter_times: list = field(default_factory=list)
     stall_times: list = field(default_factory=list)
+    step_ends: list = field(default_factory=list)   # perf_counter per step
+    tokens_per_step: int = 0
     failures: int = 0
     recoveries: int = 0
     recovered_at: list = field(default_factory=list)
@@ -53,19 +55,23 @@ class LoopStats:
     checkpointer: Optional[BaseCheckpointer] = None
 
     @property
-    def throughput(self) -> float:
-        total = sum(self.iter_times) + sum(self.stall_times)
-        return self.steps / total if total else 0.0
-
-    @property
     def mean_iter(self) -> float:
         return float(np.mean(self.iter_times)) if self.iter_times else 0.0
 
     @property
-    def steady_iter(self) -> float:
-        """Median iteration time excluding the first (compile-heavy) step."""
-        xs = self.iter_times[1:] if len(self.iter_times) > 1 else self.iter_times
-        return float(np.median(xs)) if xs else 0.0
+    def step_s(self) -> float:
+        """Wall seconds per step from the end of the first step to the end
+        of the last: capture, checkpointer stalls, host gaps, recoveries
+        and replays inside; the first step's compile outside."""
+        if len(self.step_ends) < 2:
+            return 0.0
+        return ((self.step_ends[-1] - self.step_ends[0])
+                / (len(self.step_ends) - 1))
+
+    @property
+    def tokens_per_s(self) -> float:
+        s = self.step_s
+        return self.tokens_per_step / s if s else 0.0
 
 
 def train(cfg: ModelConfig, rules: ShardingRules, *,
@@ -127,95 +133,122 @@ def train(cfg: ModelConfig, rules: ShardingRules, *,
 
     step_fn = jax.jit(build_train_step(cfg, mesh, rules, opt, lr_fn),
                       donate_argnums=(0,))
-    stats = LoopStats(checkpointer=checkpointer)
+    stats = LoopStats(checkpointer=checkpointer, tokens_per_step=batch * seq)
     ema_iter = None
     step = int(state.step)
 
     ob = _obs.get()
+    span = ob.tracer.span
+    host_grads = None
     while step < steps:
-        batch_np = stream.batch_at(step)
-        dbatch = device_batch(batch_np, rules)
-        t0 = time.perf_counter()
-        try:
-            if failure_plan.should_fail(step + 1):
-                # fail mid-iteration: device state for this step is lost
-                stats.failures += 1
-                raise TrainingFailure(f"injected failure at step {step + 1}")
-            with ob.tracer.span("step.compute", args={"step": step + 1}):
-                state, metrics, grads = step_fn(state, dbatch)
-                jax.block_until_ready(metrics["loss"])
-        except TrainingFailure:
-            state = None     # lost with the failure; frees HBM for the restore
-            with ob.tracer.span("recovery.restore", track="recovery",
-                                args={"failed_step": step + 1}):
-                restored = checkpointer.restore()
-            if restored is None:
-                raise
-            nr = (elastic_rules(step + 1) if callable(elastic_rules)
-                  else elastic_rules)
-            if nr is not None and nr is not rules:
-                # elastic restart: land the consolidated checkpoint on the
-                # reconfigured mesh and rebuild everything the old layout
-                # derived (step function, bucket layout, shadow plane,
-                # channel geometry)
-                rules, mesh = nr, nr.mesh
-                step_fn = jax.jit(
-                    build_train_step(cfg, mesh, rules, opt, lr_fn),
-                    donate_argnums=(0,))
-                if isinstance(checkpointer, CheckmateCheckpointer):
-                    from repro.core.elastic import rebuild_shadow
-                    checkpointer.reconfigure(
-                        rebuild_shadow(checkpointer.shadow, restored))
-                elastic_rules = None       # the switch fires once
-            state = state_from_checkpoint(restored, cfg, rules)
-            step = int(restored["step"])
-            stats.recoveries += 1
-            stats.recovered_at.append(step)
-            ob.tracer.instant("recovery.resume", track="recovery",
-                              args={"resumed_step": step})
-            ob.metrics.counter("train_recoveries_total",
-                               "Recoveries from injected failures").inc(1)
-            continue
-        iter_time = time.perf_counter() - t0
-        step += 1
-        stats.steps += 1
-        stats.iter_times.append(iter_time)
-        stats.losses.append(float(metrics["loss"]))
+        # every span carries the number of the step this iteration completes
+        with jax.profiler.StepTraceAnnotation("train", step_num=step + 1):
+            with span("data.batch", args={"step": step + 1}):
+                batch_np = stream.batch_at(step)
+            with span("data.put", args={"step": step + 1}):
+                dbatch = device_batch(batch_np, rules)
+            t0 = time.perf_counter()
+            try:
+                if failure_plan.should_fail(step + 1):
+                    # fail mid-iteration: device state for this step is lost
+                    stats.failures += 1
+                    raise TrainingFailure(
+                        f"injected failure at step {step + 1}")
+                with span("step.compute", args={"step": step + 1}):
+                    with span("step.dispatch", args={"step": step + 1}):
+                        state, metrics, grads = step_fn(state, dbatch)
+                    with span("step.wait", args={"step": step + 1}):
+                        jax.block_until_ready(metrics["loss"])
+            except TrainingFailure:
+                state = None   # lost with the failure; frees HBM for the restore
+                with span("recovery.restore", track="recovery",
+                          args={"failed_step": step + 1}):
+                    restored = checkpointer.restore()
+                if restored is None:
+                    raise
+                nr = (elastic_rules(step + 1) if callable(elastic_rules)
+                      else elastic_rules)
+                if nr is not None and nr is not rules:
+                    # elastic restart: land the consolidated checkpoint on
+                    # the reconfigured mesh and rebuild everything the old
+                    # layout derived (step function, bucket layout, shadow
+                    # plane, channel geometry)
+                    rules, mesh = nr, nr.mesh
+                    step_fn = jax.jit(
+                        build_train_step(cfg, mesh, rules, opt, lr_fn),
+                        donate_argnums=(0,))
+                    if isinstance(checkpointer, CheckmateCheckpointer):
+                        from repro.core.elastic import rebuild_shadow
+                        checkpointer.reconfigure(
+                            rebuild_shadow(checkpointer.shadow, restored))
+                    elastic_rules = None       # the switch fires once
+                with span("recovery.place", track="recovery",
+                          args={"step": int(restored["step"])}):
+                    state = state_from_checkpoint(restored, cfg, rules)
+                step = int(restored["step"])
+                stats.recoveries += 1
+                stats.recovered_at.append(step)
+                ob.tracer.instant("recovery.resume", track="recovery",
+                                  args={"resumed_step": step})
+                ob.metrics.counter("train_recoveries_total",
+                                   "Recoveries from injected failures").inc(1)
+                continue
+            iter_time = time.perf_counter() - t0
+            step += 1
+            stats.steps += 1
+            stats.iter_times.append(iter_time)
 
-        # straggler observability: EMA-based slow-iteration flag
-        if ema_iter is None:
-            ema_iter = iter_time
-        else:
-            if iter_time > straggler_factor * ema_iter:
-                stats.straggler_flags.append(step)
-            ema_iter = straggler_ema * ema_iter + (1 - straggler_ema) * iter_time
+            # straggler observability: EMA-based slow-iteration flag
+            if ema_iter is None:
+                ema_iter = iter_time
+            else:
+                if iter_time > straggler_factor * ema_iter:
+                    stats.straggler_flags.append(step)
+                ema_iter = (straggler_ema * ema_iter
+                            + (1 - straggler_ema) * iter_time)
 
-        lr = float(metrics["lr"])
-        scale = 1.0
-        if opt.grad_clip:
-            gn = float(metrics["grad_norm"])
-            scale = min(1.0, opt.grad_clip / (gn + 1e-9))
-        host_grads = None
-        if isinstance(grads, dict) and getattr(checkpointer,
-                                               "consumes_grads", False):
-            # the capture's device->host DMA; the channel packs these host
-            # leaves straight into the wire buffer (one further pass).
-            # Copy-persist baselines never read grads, so they don't pay it.
-            with ob.tracer.span("capture.d2h", args={"step": step}):
-                host_grads = {k: np.asarray(v) for k, v in grads.items()}
-        # the device gradients are dead once copied: free them before the
-        # next step runs, so two steps' gradients never share HBM
-        for g in jax.tree.leaves(grads):
-            g.delete()
-        del grads
-        stall = checkpointer.on_step(StepEvent(
-            step=step, grads=host_grads, lr=lr, grad_scale=scale,
-            iter_time=iter_time,
-            state_fn=lambda: checkpoint_from_state(state)))
-        stats.stall_times.append(stall)
-        ob.metrics.counter("train_steps_total", "Completed iterations").inc(1)
-        if step_hook is not None:
-            step_hook(step, state, stats)
+            scale = 1.0
+            with span("step.readback", args={"step": step}):
+                stats.losses.append(float(metrics["loss"]))
+                lr = float(metrics["lr"])
+                if opt.grad_clip:
+                    gn = float(metrics["grad_norm"])
+                    scale = min(1.0, opt.grad_clip / (gn + 1e-9))
+            if host_grads is not None:
+                # the last step's host copy, given back before the next is
+                # made (its memory returns here, in the device's gap)
+                with span("capture.free", args={"step": step}):
+                    host_grads = None
+            if isinstance(grads, dict) and getattr(checkpointer,
+                                                   "consumes_grads", False):
+                # the capture's device->host DMA; the channel packs these
+                # host leaves straight into the wire buffer (one further
+                # pass). Copy-persist baselines never read grads, so they
+                # don't pay it.
+                nbytes = sum(g.nbytes for g in grads.values())
+                with span("capture.d2h", args={"step": step,
+                                               "bytes": nbytes}):
+                    host_grads = {k: np.asarray(v) for k, v in grads.items()}
+                ob.metrics.counter(
+                    "capture_bytes_total",
+                    "Gradient bytes copied off the device").inc(nbytes)
+            # the device gradients are dead once copied: free them before
+            # the next step runs, so two steps' gradients never share HBM
+            with span("step.free", args={"step": step}):
+                for g in jax.tree.leaves(grads):
+                    g.delete()
+                del grads
+            stall = checkpointer.on_step(StepEvent(
+                step=step, grads=host_grads, lr=lr, grad_scale=scale,
+                iter_time=iter_time,
+                state_fn=lambda: checkpoint_from_state(state)))
+            stats.stall_times.append(stall)
+            stats.step_ends.append(time.perf_counter())
+            ob.metrics.counter("train_steps_total",
+                               "Completed iterations").inc(1)
+            if step_hook is not None:
+                with span("loop.hook", args={"step": step}):
+                    step_hook(step, state, stats)
 
     checkpointer.finalize()
     return state, stats

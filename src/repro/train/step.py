@@ -116,10 +116,6 @@ def build_train_step(cfg: ModelConfig, mesh, rules: ShardingRules,
         ob.metrics.gauge("capture_bytes",
                          "Per-step reduced-gradient capture size").set(
             nbytes, arch=cfg.name)
-        ob.tracer.instant("train_step.build",
-                          args={"arch": cfg.name,
-                                "microbatches": cfg.microbatches,
-                                "return_grads": return_grads})
     return train_step
 
 

@@ -147,6 +147,158 @@ def test_manual_clock_golden_scenario_trace_is_deterministic():
     assert one_run() == one_run()
 
 
+# -- the profiler's own clock ---------------------------------------------------
+
+@pytest.fixture(scope="module")
+def profiled(tmp_path_factory):
+    """One profiler session on the CPU: an enabled tracer's spans (one
+    nested in the other), an instant, and a disabled tracer's span. Returns
+    the tracer's events and the XPlane host plane's {name: [duration_s]}."""
+    import glob
+    import jax
+    from jax.profiler import ProfileData
+    tr, off = Tracer(), Tracer(enabled=False)
+    out = tmp_path_factory.mktemp("xplane")
+    with jax.profiler.trace(str(out)):
+        with tr.span("obs.mirrored"):
+            time.sleep(0.02)
+            with tr.span("obs.child"):
+                time.sleep(0.005)
+        tr.instant("obs.instant")
+        with off.span("obs.disabled"):
+            time.sleep(0.005)
+    path, = glob.glob(str(out / "**" / "*.xplane.pb"), recursive=True)
+    host = {}
+    for plane in ProfileData.from_file(path).planes:
+        if plane.name.startswith("/host:"):
+            for line in plane.lines:
+                for ev in line.events:
+                    host.setdefault(ev.name, []).append(ev.duration_ns * 1e-9)
+    return {e["name"]: e for e in tr.events()}, host
+
+
+def test_span_is_mirrored_into_the_profilers_host_plane(profiled):
+    events, host = profiled
+    for name in ("obs.mirrored", "obs.child"):
+        assert len(host.get(name, [])) == 1, name       # its exact name
+        assert host[name][0] == pytest.approx(events[name]["dur"] * 1e-6,
+                                              abs=1e-3)
+
+
+def test_instant_leaves_no_annotation(profiled):
+    events, host = profiled
+    assert "obs.instant" in events
+    assert "obs.instant" not in host
+
+
+def test_disabled_tracer_opens_no_annotation(profiled):
+    _, host = profiled
+    assert "obs.disabled" not in host
+
+
+def test_spans_export_their_parents_name():
+    import threading
+    tr = Tracer(clock=ManualClock(0.0))
+    with tr.span("step.compute", args={"step": 1}):
+        with tr.span("step.wait", args={"step": 1}):
+            pass
+
+        def other_thread():                 # a stack of its own
+            with tr.span("shadow.apply"):
+                pass
+        t = threading.Thread(target=other_thread)
+        t.start()
+        t.join()
+    with tr.span("loop.hook"):
+        pass
+    ev = {e["name"]: e for e in tr.events()}
+    assert ev["step.wait"]["args"] == {"step": 1, "parent": "step.compute"}
+    assert ev["step.compute"]["args"] == {"step": 1}
+    assert "args" not in ev["shadow.apply"]
+    assert "args" not in ev["loop.hook"]
+
+
+# -- the training loop's spans ----------------------------------------------------
+
+LOOP_SPANS = ("data.batch", "data.put", "step.dispatch", "step.wait",
+              "step.readback", "step.free", "loop.hook")
+
+
+@pytest.fixture(scope="module")
+def traced_train():
+    """Three steps of a tiny model through the in-process channel into a
+    two-node shadow, under an enabled session."""
+    import jax
+    import repro.configs as C
+    from repro.core.buckets import layout_for_tree
+    from repro.core.channel import InProcessChannel
+    from repro.dist.sharding import ShardingRules, make_smoke_mesh
+    from repro.optim import OptimizerConfig
+    from repro.train.loop import train
+    from repro.train.step import make_train_state
+    cfg = C.get("llama3.2-3b").reduced()
+    rules = ShardingRules(make_smoke_mesh())
+    s0 = make_train_state(jax.random.PRNGKey(0), cfg, rules)
+    layout = layout_for_tree(s0.params)
+    hooked = []
+    with obs.enabled_session() as ob:
+        _, stats = train(cfg, rules, steps=3, batch=2, seq=16,
+                         opt=OptimizerConfig(lr=1e-3), state=s0,
+                         channel=InProcessChannel(),
+                         step_hook=lambda step, *_: hooked.append(step))
+        stats.checkpointer.shadow.shutdown()
+        snap = ob.metrics.snapshot()["metrics"]
+    return ob.tracer.events(), snap, stats, layout, hooked
+
+
+def test_every_loop_span_once_per_step(traced_train):
+    events, _, stats, _, hooked = traced_train
+    assert hooked == [1, 2, 3]
+    for name in LOOP_SPANS:
+        steps = sorted(e["args"]["step"] for e in events if e["name"] == name)
+        assert steps == [1, 2, 3], name
+    parent = {e["name"]: e["args"].get("parent") for e in events
+              if e["name"] in LOOP_SPANS}
+    assert parent["step.dispatch"] == parent["step.wait"] == "step.compute"
+    assert parent["data.batch"] is None and parent["loop.hook"] is None
+
+
+def test_copies_carry_the_layouts_gradient_bytes(traced_train):
+    events, snap, _, layout, _ = traced_train
+    freed = [e["args"]["step"] for e in events if e["name"] == "capture.free"]
+    assert freed == [2, 3]              # the last step's copy, given back
+    for name in ("capture.d2h", "bucket.pack"):
+        got = [e["args"]["bytes"] for e in events if e["name"] == name]
+        assert got == [layout.total_bytes] * 3, name
+    packs = [e["args"]["buckets"] for e in events if e["name"] == "bucket.pack"]
+    assert packs == [len(layout.buckets)] * 3
+    for counter in ("capture_bytes_total", "channel_pack_bytes_total"):
+        total = sum(s["value"] for s in snap[counter]["samples"])
+        assert total == 3 * layout.total_bytes, counter
+
+
+def test_shadow_bootstrap_spans_its_copy_and_each_install(traced_train):
+    events, _, stats, layout, _ = traced_train
+    boot = [e for e in events if e["name"].startswith("shadow.bootstrap")]
+    kinds = {e["name"]: e for e in boot}
+    assert set(kinds) == {"shadow.bootstrap", "shadow.bootstrap.d2h",
+                          "shadow.bootstrap.install"}
+    d2h = kinds["shadow.bootstrap.d2h"]
+    assert d2h["args"]["parent"] == "shadow.bootstrap"
+    assert d2h["args"]["bytes"] == 3 * layout.total_bytes   # params, mu, nu
+    nodes = sorted(e["args"]["node"] for e in boot
+                   if e["name"] == "shadow.bootstrap.install")
+    assert nodes == list(range(stats.checkpointer.shadow.n_nodes))
+
+
+def test_tokens_per_s_runs_from_the_first_steps_end_to_the_last():
+    from repro.train.loop import LoopStats
+    stats = LoopStats(tokens_per_step=100, step_ends=[10.0, 10.5, 11.0, 12.0])
+    assert stats.step_s == pytest.approx(2.0 / 3)
+    assert stats.tokens_per_s == pytest.approx(150.0)
+    assert LoopStats(tokens_per_step=100, step_ends=[1.0]).tokens_per_s == 0.0
+
+
 # -- disabled hot paths -------------------------------------------------------
 
 def test_disabled_hot_path_is_noop_and_cheap():

@@ -512,7 +512,7 @@ class ShadowCluster:
                 batch = []
             if batch:
                 items = []
-                for step, lr, scale, grads, flats in batch:
+                for step, lr, scale, grads, flats, _ in batch:
                     if flats is None:
                         # legacy leaf-tree hand-off: bucket packing happens
                         # HERE, on the shadow node — the caller only
@@ -521,6 +521,9 @@ class ShadowCluster:
                                  for bid in node.bucket_ids}
                     items.append((step, lr, flats, scale))
                 node.apply_batch(items)
+                for *_, lease in batch:     # applied: the buffers go back
+                    if lease is not None:
+                        lease.release(node.bucket_ids)
                 if len(items) > 1:
                     self.batched_applies += 1
                     if len(items) > self.max_batch:
@@ -660,7 +663,7 @@ class ShadowCluster:
         if delivery.flats is not None:
             self._ingest(delivery.step, delivery.lr, None,
                          delivery.grad_scale, flats=delivery.flats,
-                         nodes=nodes)
+                         nodes=nodes, lease=delivery.lease)
         else:
             self._ingest(delivery.step, delivery.lr, delivery.grads,
                          delivery.grad_scale, nodes=nodes)
@@ -679,7 +682,8 @@ class ShadowCluster:
     def _ingest(self, step: int, lr: float, grads: Optional[dict],
                 grad_scale: float = 1.0,
                 flats: Optional[dict] = None,
-                nodes: Optional[set] = None):
+                nodes: Optional[set] = None,
+                lease=None):
         """Apply one iteration's reduced gradients, each node its partition.
 
         ``flats`` (the wire-layout delivery payload) is handed to nodes as
@@ -687,7 +691,9 @@ class ShadowCluster:
         — and each node sees ONLY its owned buckets (the sharded transport
         may not even have the others). Async mode enqueues a REFERENCE only
         — any (legacy) packing and the optimizer replay run on the shadow
-        workers, off the training critical path.
+        workers, off the training critical path. Each node claims its
+        buckets of ``lease`` (`repro.core.channel.WireLease`) here and
+        releases them once its apply has finished.
         """
         self.train_step_seen = step
         targets = [n for n in self.nodes
@@ -701,7 +707,9 @@ class ShadowCluster:
                 self._drained[node.node_id].clear()
                 sub = None if flats is None else \
                     {bid: flats[bid] for bid in node.bucket_ids}
-                q.put((step, lr, grad_scale, grads, sub))
+                if lease is not None:
+                    lease.claim(node.bucket_ids)
+                q.put((step, lr, grad_scale, grads, sub, lease))
                 # mutex-based depth (queue.qsize() is racy and unimplemented
                 # on some platforms); put() precedes, so depth >= 1 here
                 depth = self._pending(q)
@@ -719,9 +727,13 @@ class ShadowCluster:
             flats = {b.bucket_id: pack_bucket(b, grads, xp=np)
                      for b in self.layout.buckets if b.bucket_id in need}
         for node in targets:
+            if lease is not None:
+                lease.claim(node.bucket_ids)
             node.apply(step, lr,
                        {bid: flats[bid] for bid in node.bucket_ids},
                        grad_scale)
+            if lease is not None:
+                lease.release(node.bucket_ids)
         if self.durability is not None:
             self.durability.notify(step)          # queue puts only
 

@@ -272,9 +272,15 @@ def test_copies_carry_the_layouts_gradient_bytes(traced_train):
         assert got == [layout.total_bytes] * 3, name
     packs = [e["args"]["buckets"] for e in events if e["name"] == "bucket.pack"]
     assert packs == [len(layout.buckets)] * 3
+    # the sync shadow gives each step's wire buffers back: the next reuses
+    reused = [e["args"]["reused"] for e in events if e["name"] == "bucket.pack"]
+    assert reused == [0] + [layout.total_bytes] * 2
     for counter in ("capture_bytes_total", "channel_pack_bytes_total"):
         total = sum(s["value"] for s in snap[counter]["samples"])
         assert total == 3 * layout.total_bytes, counter
+    total = sum(s["value"] for s in
+                snap["channel_pack_reused_bytes_total"]["samples"])
+    assert total == 2 * layout.total_bytes
 
 
 def test_shadow_bootstrap_spans_its_copy_and_each_install(traced_train):

@@ -7,6 +7,8 @@ scenario replays bit-identically through the bundle machinery."""
 import dataclasses
 import json
 import queue
+import threading
+import time
 
 import numpy as np
 import pytest
@@ -175,6 +177,145 @@ def test_cluster_refuses_partial_delivery_for_dead_owner():
     assert e.value.missing_buckets == {2: tuple(shadow.nodes[2].bucket_ids)}
     assert e.value.partial["step"] == 1         # survivors applied step 1
     chan.close()
+
+
+# -- the wire-buffer pool on the paths that never apply ----------------------
+
+def _grads(params: dict, step: int) -> dict:
+    rng = np.random.default_rng(100 + step)
+    return {k: rng.standard_normal(v.shape).astype(np.float32) * 0.1
+            for k, v in params.items()}
+
+
+def _oracle(layout, state: tuple, step0: int, grad_steps: dict) -> dict:
+    """A 1-node sync cluster seeded with ``state`` at ``step0``, fed
+    ``{step: grads}`` through a channel of its own."""
+    oracle = ShadowCluster(layout, OptimizerConfig(lr=1e-3), n_nodes=1)
+    oracle.bootstrap(*state, step0)
+    chan = InProcessChannel()
+    chan.open(layout)
+    for step, grads in grad_steps.items():
+        chan.send(StepEvent(step=step, grads=grads, lr=1e-3))
+        (d,) = chan.poll()
+        oracle.on_delivery(d)
+    return oracle.consolidate()
+
+
+def _assert_same(got: dict, want: dict):
+    assert got["step"] == want["step"]
+    for part in ("params", "mu", "nu"):
+        for k in want[part]:
+            assert np.array_equal(got[part][k], want[part][k]), (part, k)
+
+
+def _addrs(flats: dict, ids=None) -> set:
+    return {f.ctypes.data for b, f in flats.items() if ids is None or b in ids}
+
+
+def test_deliveries_purged_by_bootstrap_give_nothing_back():
+    """Deliveries still queued when ``bootstrap`` re-seeds the cluster are
+    never applied and never given back: their flats keep their gradients,
+    the next send reuses only the buffers of the delivery that was applied,
+    and the re-seeded stream ends bit-identical to a fresh oracle."""
+    params = _tree(8)
+    layout = layout_for_tree(params, cap_bytes=96)
+    shadow = ShadowCluster(layout, OptimizerConfig(lr=1e-3), n_nodes=2,
+                           async_mode=True)
+    shadow.bootstrap(params, _zeros_like(params), _zeros_like(params), 0)
+    # each worker holds its first delivery until bootstrap has purged the
+    # two queued behind it (its queue's count then drops to the one apply
+    # in flight), so the purge is not raced
+    started = [threading.Event() for _ in shadow.nodes]
+    go = [threading.Event() for _ in shadow.nodes]
+    for node in shadow.nodes:
+        def held(*a, _o=node._apply, _node=node):
+            _node._apply = _o
+            started[_node.node_id].set()
+            go[_node.node_id].wait(30)
+            return _o(*a)
+        node._apply = held
+
+    def release_each_after_its_purge():
+        for n, q in enumerate(shadow._queues):
+            deadline = time.monotonic() + 30
+            while shadow._pending(q) != 1 and time.monotonic() < deadline:
+                time.sleep(0.001)
+            go[n].set()
+    chan = InProcessChannel()
+    chan.open(layout)
+    sent = {}
+    try:
+        for step in (1, 2, 3):
+            sent[step] = _grads(params, step)
+            chan.send(StepEvent(step=step, grads=sent[step], lr=1e-3))
+            (sent[step, "d"],) = chan.poll()
+            shadow.on_delivery(sent[step, "d"])
+        assert all(e.wait(30) for e in started)
+        threading.Thread(target=release_each_after_its_purge,
+                         daemon=True).start()
+        seed = (params, _grads(params, 7), _zeros_like(params))
+        shadow.bootstrap(*seed, 10)
+        purged = _addrs(sent[2, "d"].flats) | _addrs(sent[3, "d"].flats)
+        for step in (11, 12):
+            sent[step] = _grads(params, step)
+            chan.send(StepEvent(step=step, grads=sent[step], lr=1e-3))
+            (d,) = chan.poll()
+            assert not _addrs(d.flats) & purged
+            if step == 11:
+                assert _addrs(d.flats) == _addrs(sent[1, "d"].flats)
+            shadow.on_delivery(d)
+        for step in (2, 3):
+            for k in params:
+                assert np.array_equal(sent[step, "d"].grads[k],
+                                      sent[step][k])
+        got = shadow.consolidate(timeout=60)
+    finally:
+        shadow.shutdown()
+    _assert_same(got, _oracle(layout, seed, 10,
+                              {s: sent[s] for s in (11, 12)}))
+
+
+@pytest.mark.parametrize("async_mode", [False, True])
+def test_dead_node_gives_nothing_back_until_revived(async_mode):
+    """A killed node's buckets are never claimed, so every send while it is
+    dead packs them into fresh buffers and the survivors' into given-back
+    ones; after the re-seed every bucket reuses again, and the stream ends
+    bit-identical to a fresh oracle."""
+    params = _tree(9)
+    layout = layout_for_tree(params, cap_bytes=96)
+    shadow = ShadowCluster(layout, OptimizerConfig(lr=1e-3), n_nodes=2,
+                           async_mode=async_mode,
+                           max_lag_steps=1 if async_mode else None)
+    shadow.bootstrap(params, _zeros_like(params), _zeros_like(params), 0)
+    dead = set(shadow.nodes[1].bucket_ids)
+    assert dead and set(shadow.nodes[0].bucket_ids)
+    chan = InProcessChannel()
+    chan.open(layout)
+    sent, kept = {}, []
+    try:
+        for step in range(1, 10):
+            if step == 3:
+                shadow.consolidate(timeout=60)   # steps 1, 2 given back
+                shadow.kill_node(1)
+            if step == 7:
+                seed = (params, _grads(params, 99), _zeros_like(params))
+                shadow.bootstrap(*seed, 6)       # replacement node seeded
+            sent[step] = _grads(params, step)
+            chan.send(StepEvent(step=step, grads=sent[step], lr=1e-3))
+            (d,) = chan.poll()
+            before = set().union(*(_addrs(k.flats) for k in kept))
+            if step in (5, 6):    # steps 3, 4 took the two given-back sets
+                assert not _addrs(d.flats, dead) & before
+                assert _addrs(d.flats, set(d.flats) - dead) <= before
+            if step == 9:                        # revived: all reused
+                assert _addrs(d.flats) <= before
+            kept.append(d)
+            shadow.on_delivery(d)
+        got = shadow.consolidate(timeout=60)
+    finally:
+        shadow.shutdown()
+    _assert_same(got, _oracle(layout, seed, 6,
+                              {s: sent[s] for s in (7, 8, 9)}))
 
 
 # -- queue-depth accounting without queue.qsize ------------------------------

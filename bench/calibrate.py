@@ -13,7 +13,8 @@ of the batch left out, the mean taken over the rest" (the reference on the
 first half of each batch), each read against the float32 reference; and,
 with a checkpointer, the shadow rounded to bfloat16 against the live state.
 A step that leaves the state unchanged reads 1 on ``change_norm`` and
-``grad_norm`` by their definitions and needs no run.
+``grad_norm`` by their definitions and needs no run. The reference is the
+configuration's own model (`bench.reference.model_for`).
 
 One JSON object per seed goes to standard output and, with ``--out``, is
 appended to that file. The benchmark's own runs never run this.

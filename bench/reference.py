@@ -1,38 +1,96 @@
-"""Plain float32 reference of the benchmark's dense decoder and its AdamW step.
+"""Plain float32 reference of the benchmark's models and their AdamW step.
 
 Written from the configuration file alone (``bench/configs/*.json``): the
-block those files state (RMSNorm, rotate-half RoPE, causal softmax
-attention, SwiGLU, an untied head), the weight convention they state, and
-AdamW with decoupled weight decay. It imports nothing of the system under
-test and takes nothing it made: weights come from the seed by the stated
+block the file states, the weight convention it states, and AdamW with
+decoupled weight decay. It imports nothing of the system under test and
+takes nothing it made: weights come from the seed by the stated
 convention, tokens from `bench/gen.py`.
 
-Every matrix product runs at ``Precision.HIGHEST``. A batch is taken one
-row at a time, each row's layers rematerialised, so that three steps of a
-model that fills the chip fit beside their own optimizer state.
+Every matrix product runs at ``Precision.HIGHEST``. The dense decoder
+(RMSNorm, rotate-half RoPE, causal softmax attention, SwiGLU, an untied
+head) takes a batch one row at a time, each row's layers rematerialised,
+so that three steps of a model that fills the chip fit beside their own
+optimizer state.
 
 ``mode="fp8"`` is the control: the same reference with every matrix
 product's operands rounded to scaled float8 (e4m3 forward, e5m2 for the
 gradients flowing back), the precision below the bfloat16 the
 configuration computes in.
+
+The model is the configuration's own (`model_for`): a configuration file
+with a ``"reference"`` key names a module, a path relative to ``bench/``,
+that states it; without one it is the dense decoder of this file
+(`DENSE`). A model module provides exactly three functions:
+
+* ``leaf_specs(cfg)``: weight name -> (shape, init), init one of
+  `init_leaf`'s;
+* ``batch_grad(params, tokens, labels, cfg, mode, rows)``: the mean loss
+  (a float) and the mean gradient over ``rows`` of the batch (all rows
+  where ``rows`` is None), each matrix product through `product` so that
+  ``mode="fp8"`` reaches it;
+* ``flops_per_token(cfg, seq)``: model FLOPs of one token of a training
+  step (`bench/flops.py`).
+
+What every model shares stays here: the seed's keys and the weight
+convention, the matrix product and its float8 control, AdamW, the
+readings and the three compared steps (`run`).
 """
 from __future__ import annotations
 
+import importlib.util
 import math
+import types
 from functools import partial
+from pathlib import Path
 
 import numpy as np
 
 import jax
 import jax.numpy as jnp
 
+from bench import flops
+
 HIGHEST = jax.lax.Precision.HIGHEST
+BENCH = Path(__file__).resolve().parent
+MODEL_FUNCTIONS = ("leaf_specs", "batch_grad", "flops_per_token")
+
+_MODELS = {}
+
+
+def model_for(cfg: dict):
+    """The module that states the configuration's model: the file its
+    ``reference`` key names, else the dense decoder of this file."""
+    rel = cfg.get("reference")
+    if rel is None:
+        return DENSE
+    path = (BENCH / rel).resolve()
+    if path not in _MODELS:
+        if not path.is_file():
+            raise FileNotFoundError(
+                f"configuration {cfg.get('name')!r} names the reference "
+                f"{rel!r}, and bench/{rel} is not a file")
+        spec = importlib.util.spec_from_file_location(
+            "bench_model_" + path.stem.replace(".", "_").replace("-", "_"),
+            path)
+        mod = importlib.util.module_from_spec(spec)
+        spec.loader.exec_module(mod)
+        missing = [f for f in MODEL_FUNCTIONS if not hasattr(mod, f)]
+        if missing:
+            raise AttributeError(f"configuration {cfg.get('name')!r}: "
+                                 f"bench/{rel} lacks {missing}")
+        _MODELS[path] = mod
+    return _MODELS[path]
 
 
 # -- weights -------------------------------------------------------------------
 
 def leaf_specs(cfg: dict) -> dict:
-    """name -> (shape, init) for every weight the configuration has."""
+    """name -> (shape, init) for every weight the configuration's model has."""
+    return model_for(cfg).leaf_specs(cfg)
+
+
+def dense_leaf_specs(cfg: dict) -> dict:
+    """The dense decoder's weights."""
     L, d, f, V = (cfg["num_layers"], cfg["d_model"], cfg["d_ff"],
                   cfg["vocab_size"])
     hq = cfg["num_heads"] * cfg["head_dim"]
@@ -114,14 +172,16 @@ _fp8_cotangent.defvjp(lambda y: (y, None),
                       lambda _, g: (_round8(g, jnp.float8_e5m2),))
 
 
-def _product(spec, a, b, mode):
+def product(spec, a, b, mode):
+    """``einsum(spec, a, b)`` at HIGHEST; under ``mode="fp8"`` its operands
+    and the gradient flowing back rounded to float8."""
     if mode == "fp8":
         return _fp8_cotangent(jnp.einsum(spec, _fp8_operand(a),
                                          _fp8_operand(b), precision=HIGHEST))
     return jnp.einsum(spec, a, b, precision=HIGHEST)
 
 
-# -- the block -------------------------------------------------------------------
+# -- the dense decoder ---------------------------------------------------------
 
 def rmsnorm(x, w, eps):
     return x * jax.lax.rsqrt(jnp.mean(x * x, axis=-1, keepdims=True) + eps) * w
@@ -142,7 +202,7 @@ def layer(x, lp, cfg, mode):
     s = x.shape[0]
     h, kv, hd = cfg["num_heads"], cfg["num_kv_heads"], cfg["head_dim"]
     eps = cfg["norm_eps"]
-    mm = partial(_product, mode=mode)
+    mm = partial(product, mode=mode)
     xn = rmsnorm(x, lp["attn_norm"], eps)
     q = rope(mm("sd,de->se", xn, lp["wq"]).reshape(s, h, hd),
              cfg["rope_theta"])
@@ -178,7 +238,7 @@ def row_loss(params, tokens, labels, cfg, mode):
     for i in range(cfg["num_layers"]):
         x = body(x, {k: params[k][i] for k in LAYER_LEAVES})
     x = rmsnorm(x, params["final_norm"], cfg["norm_eps"])
-    logits = _product("sd,dv->sv", x, params["unembed"], mode)
+    logits = product("sd,dv->sv", x, params["unembed"], mode)
     nll = (jax.nn.logsumexp(logits, axis=-1)
            - jnp.take_along_axis(logits, labels[:, None], axis=-1)[:, 0])
     return jnp.mean(nll)
@@ -208,7 +268,13 @@ def _adamw(params, grads, mu, nu, step, lr, opt_items):
 
 def batch_grad(params, tokens, labels, cfg, mode="f32", rows=None):
     """Mean loss and mean gradient over ``rows`` of the batch (all rows by
-    default), one row per call."""
+    default), as the configuration's model takes them."""
+    return model_for(cfg).batch_grad(params, tokens, labels, cfg, mode, rows)
+
+
+def dense_batch_grad(params, tokens, labels, cfg, mode="f32", rows=None):
+    """The dense decoder's: its loss is a mean of per-row losses, so the
+    batch is taken one row per call."""
     rows = range(tokens.shape[0]) if rows is None else rows
     items = tuple((k, cfg[k]) for k in MODEL_KEYS)
     acc = (jnp.zeros((), jnp.float32),
@@ -218,6 +284,11 @@ def batch_grad(params, tokens, labels, cfg, mode="f32", rows=None):
                           jnp.asarray(labels[r]), items, mode)
     n = float(len(rows))
     return float(acc[0]) / n, jax.tree.map(lambda g: g / n, acc[1])
+
+
+DENSE = types.SimpleNamespace(leaf_specs=dense_leaf_specs,
+                              batch_grad=dense_batch_grad,
+                              flops_per_token=flops.dense_flops_per_token)
 
 
 def _leaf_reading(x, idx):
